@@ -8,7 +8,9 @@ three gradient schemes and counts collectives in the compiled HLO:
     arena+int8  bucket psum with shared-scale int8 + error feedback
 
 Runs in a subprocess so XLA_FLAGS can force 8 host devices without touching
-this process's device count.
+this process's device count.  The child is held to the CPU
+(``JAX_PLATFORMS=cpu``): it counts HLO on virtual CPU devices and must not
+reach for an accelerator the parent may hold.
 """
 from __future__ import annotations
 
@@ -60,6 +62,7 @@ print(json.dumps(out))
 def run(out=sys.stdout):
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                          capture_output=True, text=True, timeout=900,
                          cwd=os.path.dirname(os.path.dirname(

@@ -5,7 +5,7 @@ Per (arch x shape x mesh) cell:
     memory_s     = HLO_bytes_corrected / HBM_bw
     collective_s = collective_bytes_corrected / ICI_bw
 with the scan-trip correction from the per-layer probes (see launch/probe.py)
-and v5e constants.  MODEL_FLOPS is the analytic 6*N_active*D (train) /
+and the peaks of the cell's ``device_kind`` (:data:`PEAKS`).  MODEL_FLOPS is the analytic 6*N_active*D (train) /
 2*N_active*D (inference) + attention-context term; the useful-compute ratio
 MODEL_FLOPS / (HLO_FLOPs * devices) catches remat/dispatch waste.
 """
@@ -20,9 +20,22 @@ from typing import Dict, List, Optional
 from repro.configs.shapes import SHAPES
 from repro.models import registry
 
-PEAK_FLOPS = 197e12          # bf16 / chip (v5e)
-HBM_BW = 819e9               # B/s per chip
-ICI_BW = 50e9                # B/s per link (conservative: 1 link budgeted)
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s ICI per chip over 4 links (one 50 GB/s link is budgeted).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks_for(device_kind: Optional[str]) -> Dict[str, float]:
+    """The peak table row of ``device_kind``; a kind not in the table is
+    an error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)}); add a row to PEAKS with "
+                       f"its source")
+    return PEAKS[device_kind]
 
 
 # ---------------------------------------------------------------------------
@@ -128,9 +141,10 @@ def analyse(cell: Dict) -> Optional[Dict]:
         "flops": cell["flops"], "bytes": cell["bytes_accessed"],
         "collective_bytes": cell["collectives"]["total_bytes"]}
     n_dev = cell["devices"]
-    compute_s = corr["flops"] / PEAK_FLOPS
-    memory_s = corr["bytes"] / HBM_BW
-    coll_s = corr["collective_bytes"] / ICI_BW
+    peaks = peaks_for(cell.get("device_kind"))
+    compute_s = corr["flops"] / peaks["flops"]
+    memory_s = corr["bytes"] / peaks["hbm_bw"]
+    coll_s = corr["collective_bytes"] / peaks["ici_bw"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     dom = max(terms, key=terms.get)
     mf = model_flops(cfg, shape)

@@ -36,7 +36,6 @@ from typing import Optional, Sequence
 import numpy as np
 
 from repro.analysis import sanitizer
-from repro.core.engine import TransferSession
 
 from .timer import bench
 
@@ -60,6 +59,10 @@ def _tree(n: int):
 
 def _steady_pass_us(n: int, *, sanitize: bool, min_time: float) -> float:
     """Mean us/pass of a steady mutate-then-ship program loop."""
+    # imported here: the --smoke leg spawns children that may need the
+    # accelerator, so its parent never loads the engine (or JAX)
+    from repro.core.engine import TransferSession
+
     prev = sanitizer._ACTIVE
     sanitizer._ACTIVE = None
     if sanitize:

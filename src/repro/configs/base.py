@@ -58,7 +58,6 @@ class ModelConfig:
     compute_dtype: str = "bfloat16"
     remat: str = "dots"                  # none | dots | full
     optimizer: str = "adamw"             # adamw | adafactor
-    use_pallas: bool = False             # TPU kernels (interpret-tested on CPU)
     micro_batches: int = 1               # gradient-accumulation steps
 
     # sharding rule overrides (logical axis -> mesh axes tuple / None),
@@ -121,7 +120,6 @@ class ModelConfig:
             compute_dtype="float32",
             remat="none",
             micro_batches=1,
-            use_pallas=False,
         )
 
 

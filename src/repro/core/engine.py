@@ -413,6 +413,16 @@ def repack_traced(buffers: Buffers, layout: ArenaLayout, tree: Any) -> Buffers:
 FENCE_DEPTH = 8
 
 
+def _drop_ready(fence: List[List[Any]]) -> None:
+    """Forget fence groups whose values are all ready (or deleted):
+    nothing they guard is in flight any more, and holding them keeps the
+    device copies of earlier passes alive.  On one TPU v5e the steady pass
+    of mamba2-1.3b's ServeState ran out of memory while the cold pass's
+    fences still held its 2.9 GB params bucket and leaves."""
+    fence[:] = [grp for grp in fence
+                if not all(v.is_deleted() or v.is_ready() for v in grp)]
+
+
 class ArenaEntry:
     """Everything reusable about one (treedef, signature, alignment, shards)
     point: the layout, double-buffered host staging per bucket with content
@@ -523,6 +533,7 @@ class ArenaEntry:
         """Register device values that (may) read the bucket's active buffer.
         ``pack_host`` waits them before rewriting that buffer."""
         fence = self._fences[bucket][self._active[bucket]]
+        _drop_ready(fence)
         fence.append(list(values))
         while len(fence) > FENCE_DEPTH:
             jax.block_until_ready(fence.pop(0))
@@ -539,6 +550,11 @@ class ArenaEntry:
             fence.clear()
         if _sanitizer._ACTIVE is not None:
             _sanitizer._ACTIVE.on_fence_wait(self, bucket, buf_idx)
+
+    def _drop_ready_fences(self) -> None:
+        for fences in self._fences.values():
+            for fence in fences:
+                _drop_ready(fence)
 
     def take_fence_wait(self) -> float:
         s, self.fence_wait_s = self.fence_wait_s, 0.0
@@ -557,6 +573,7 @@ class ArenaEntry:
         leaves = jax.tree_util.tree_leaves(tree)
         if len(leaves) != self.layout.num_leaves:
             raise ValueError("tree does not match arena layout")
+        self._drop_ready_fences()
         pending: Dict[int, np.ndarray] = {}
         for i, (leaf, slot) in enumerate(zip(leaves, self.layout.slots)):
             if slot.size == 0:

@@ -205,7 +205,9 @@ def _default_dp_sharding(k: int):
             f"sharded spec names a dp{k} mesh, but only {visible} device(s) "
             f"are visible — the policy is stale for this (surviving) mesh; "
             f"re-derive it for {visible} device(s)")
-    mesh = jax.make_mesh((k,), ("data",))
+    from ..jaxenv import make_mesh
+
+    mesh = make_mesh((k,), ("data",))
     return NamedSharding(mesh, PartitionSpec("data"))
 
 

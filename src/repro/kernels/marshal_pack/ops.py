@@ -91,7 +91,7 @@ def pack_pool(pool: jax.Array, tile_map: jax.Array, interpret: bool = False
     return out.reshape(-1)
 
 
-def pack_tree(tree: Any, *, interpret: bool = True) -> Tuple[jax.Array, Any]:
+def pack_tree(tree: Any, *, interpret: bool = False) -> Tuple[jax.Array, Any]:
     """Marshal a (single-dtype) pytree into one contiguous buffer.
 
     The tile map is derived from the arena plan (requestList) for the tree
@@ -109,7 +109,7 @@ def pack_tree(tree: Any, *, interpret: bool = True) -> Tuple[jax.Array, Any]:
     return packed, meta
 
 
-def unpack_tree(packed: jax.Array, meta) -> Any:
-    pool = pack_pool(packed, meta["unpack_map"], interpret=True)
+def unpack_tree(packed: jax.Array, meta, *, interpret: bool = False) -> Any:
+    pool = pack_pool(packed, meta["unpack_map"], interpret=interpret)
     leaves = pool_to_leaves(pool, meta["shapes"], meta["dtype"])
     return jax.tree_util.tree_unflatten(meta["treedef"], leaves)

@@ -30,10 +30,16 @@ def _ssd_kernel(x_ref, dt_ref, dtA_ref, b_ref, c_ref, y_ref, st_ref, cum_ref):
     Cm = c_ref[0, 0].astype(jnp.float32)          # (Q, N)
 
     Q = x.shape[0]
-    cum = jnp.cumsum(dtA[0])                      # (Q,)
-    seg = cum[:, None] - cum[None, :]
-    mask = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    mask = row >= col
+    # running sums as matmuls against triangular ones (Mosaic has no
+    # cumsum): cum[j] = sum_{k<=j} dtA[k], as a row and as a column
+    cum_row = jax.lax.dot_general(dtA, (row <= col).astype(jnp.float32),
+                                  (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+    cum_col = jnp.sum(jnp.where(mask, dtA, 0.0), axis=1, keepdims=True)
+    seg = cum_col - cum_row                       # (Q, Q): cum[i] - cum[j]
     L = jnp.where(mask, jnp.exp(seg), 0.0)
 
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
@@ -42,14 +48,14 @@ def _ssd_kernel(x_ref, dt_ref, dtA_ref, b_ref, c_ref, y_ref, st_ref, cum_ref):
     y = jax.lax.dot_general(scores * L, dtx, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
-    decay = jnp.exp(cum[-1] - cum)
-    st = jax.lax.dot_general(dtx, Bm * decay[:, None],
+    decay = jnp.exp(jnp.sum(dtA, axis=1, keepdims=True) - cum_col)  # (Q, 1)
+    st = jax.lax.dot_general(dtx, Bm * decay,
                              (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (hd, N)
 
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
     st_ref[0, 0, 0] = st
-    cum_ref[0, 0, 0] = cum[None, :]
+    cum_ref[0, 0, 0] = cum_row
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -17,7 +17,7 @@ from .kernel import ssd_chunks
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_chunked_kernel(x, dt, A, Bm, Cm, chunk: int,
                        init_state: Optional[jax.Array] = None, *,
-                       interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+                       interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """x: (B,S,nh,hd), dt: (B,S,nh), A: (nh,), Bm/Cm: (B,S,N)."""
     Bsz, S, nh, hd = x.shape
     N = Bm.shape[-1]

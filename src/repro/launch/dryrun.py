@@ -135,6 +135,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, smoke: bool = False,
         "mesh": "x".join(map(str, mesh.devices.shape)),
         "mesh_axes": list(mesh.axis_names),
         "devices": n_dev,
+        "device_kind": mesh.devices.flat[0].device_kind,
         "lower_s": round(t_lower, 2), "compile_s": round(t_compile, 2),
         "flops": float(cost.get("flops", -1.0)) if cost else -1.0,
         "bytes_accessed": float(cost.get("bytes accessed", -1.0)) if cost else -1.0,

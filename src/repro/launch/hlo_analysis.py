@@ -73,31 +73,20 @@ def collective_stats(hlo_text: str) -> Dict[str, Any]:
 
 
 def cost_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized to one flat dict.
+    """``compiled.cost_analysis()`` as a plain dict."""
+    return dict(compiled.cost_analysis() or {})
 
-    Depending on the jax/jaxlib version this returns a dict, a singleton
-    list of dicts (one per executable), or None; every caller wants the
-    flat mapping.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
-    return dict(cost) if cost else {}
+
+MEMORY_KEYS = ("argument_size_in_bytes", "output_size_in_bytes",
+               "temp_size_in_bytes", "generated_code_size_in_bytes",
+               "alias_size_in_bytes", "peak_memory_in_bytes")
 
 
 def memory_dict(mem) -> Dict[str, float]:
+    """``compiled.memory_analysis()`` as a plain dict (empty if None)."""
     if mem is None:
         return {}
-    out = {}
-    for key in ("argument_size_in_bytes", "output_size_in_bytes",
-                "temp_size_in_bytes", "generated_code_size_in_bytes",
-                "alias_size_in_bytes", "peak_memory_in_bytes"):
-        if hasattr(mem, key):
-            try:
-                out[key] = int(getattr(mem, key))
-            except Exception:  # pragma: no cover
-                pass
-    return out
+    return {key: int(getattr(mem, key)) for key in MEMORY_KEYS}
 
 
 def op_census(hlo_text: str, top: int = 25) -> Dict[str, int]:

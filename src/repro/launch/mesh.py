@@ -13,20 +13,21 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 from jax.sharding import NamedSharding, PartitionSpec
 
+from ..jaxenv import make_mesh
 from ..models.pspec import logical_to_spec
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, pod: int = 0):
     """Small mesh for CPU tests (requires forced host device count)."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def default_rules(mesh) -> Dict[str, Optional[Tuple[str, ...]]]:

@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.configs.shapes import SHAPES
 from repro.data import Prefetcher, SyntheticLM
+from repro.jaxenv import make_mesh, use_compile_cache
 from repro.launch.mesh import (make_production_mesh, rules_for,
                                tree_shardings)
 from repro.models import pspec, registry
@@ -52,6 +53,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     api = registry.get(args.arch, smoke=args.smoke)
     cfg = api.cfg
     opt = make_optimizer(cfg.optimizer)
@@ -71,7 +73,7 @@ def main(argv=None):
                            donate_argnums=(0,))
     elif args.dp_shardmap:
         n = len(jax.devices())
-        mesh = jax.make_mesh((n, 1), ("data", "model"))
+        mesh = make_mesh((n, 1), ("data", "model"))
         dp_step = make_dp_train_step(api, opt, lr, mesh,
                                      grad_scheme=args.grad_scheme,
                                      compress=args.compress)

@@ -2,9 +2,9 @@
 
 Attention is *blockwise* over query blocks (lax.scan + per-block softmax):
 memory O(block_q * S) instead of O(S^2), which is what lets prefill_32k
-lower without materializing (B,H,S,S).  On TPU the Pallas flash kernel
-(`repro.kernels.flash_attention`) replaces the jnp path when
-``cfg.use_pallas`` is set; both share this module's semantics via ref tests.
+lower without materializing (B,H,S,S).  The models run this jnp path on
+every backend; the Pallas kernels in ``repro.kernels`` are tested against
+the same semantics but no model calls them.
 """
 from __future__ import annotations
 

@@ -76,7 +76,6 @@ def apply_moe_sharded(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
       per-expert FFN (expert-TP over ``tp_axes``, one psum)
       all_to_all back, local gather+combine.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     E, K = cfg.num_experts, cfg.experts_per_token
@@ -122,11 +121,11 @@ def apply_moe_sharded(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
 
     batch_spec = P(ep, None, None)
     w_spec = P(ep, None, tp if tp else None)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(batch_spec, P(None, None), w_spec, w_spec,
-                             P(ep, tp if tp else None, None)),
-                   out_specs=(batch_spec, P()),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(batch_spec, P(None, None), w_spec, w_spec,
+                                 P(ep, tp if tp else None, None)),
+                       out_specs=(batch_spec, P()),
+                       check_vma=False)
     out, aux = fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return out, {"moe_aux_loss": aux}
 

@@ -70,6 +70,18 @@ def serve_transfer_policy(dp_size: int = 1) -> TransferPolicy:
         "cache/**=marshal+delta; **=pointerchain")
 
 
+def serve_state(api: ModelApi, params, slots: int,
+                max_seq: int) -> Dict[str, Any]:
+    """The host-side ServeState tree a server stages: the params, the
+    ``slots x max_seq`` KV/SSM cache, and the slot table."""
+    return {
+        "params": jax.device_get(params),
+        "cache": jax.device_get(api.init_cache(slots, max_seq)),
+        "slots": {"rid": np.full((slots,), -1, np.int32),
+                  "pos": np.zeros((slots,), np.int32)},
+    }
+
+
 @dataclasses.dataclass
 class Request:
     rid: int
@@ -119,12 +131,7 @@ class Server:
 
         # host-side ServeState mirror: the tree the program compiles
         # against and the snapshot a policy swap re-stages from
-        self._host_state: Dict[str, Any] = {
-            "params": jax.device_get(params),
-            "cache": jax.device_get(api.init_cache(slots, max_seq)),
-            "slots": {"rid": np.full((slots,), -1, np.int32),
-                      "pos": np.zeros((slots,), np.int32)},
-        }
+        self._host_state = serve_state(api, params, slots, max_seq)
 
         self._decode = jax.jit(api.decode_step)
         # ONE cached prefill jit (traced per distinct prompt length), not a
@@ -271,7 +278,10 @@ class Server:
         program = self._pack_program(pack)
         faults_lib.trip(faults_lib.SERVE_PREFILL_PACK)
         future = program.to_device_async(pack)
-        dev = future.result(timeout=self.transfer_timeout_s)
+        # computed with the staged state, so placed like it (a no-op on
+        # one device; replicated over the params mesh under @dp{k})
+        dev = replicate_state(future.result(timeout=self.transfer_timeout_s),
+                              self.policy.num_shards)
 
         firsts: List[int] = []
         caches: List[Dict[str, jax.Array]] = []

@@ -109,11 +109,7 @@ def make_dp_train_step(api: ModelApi, optimizer: Optimizer,
     grad_scheme:
       "pertensor"  one psum per gradient leaf (the per-leaf deep copy)
       "arena"      pack gradients into per-dtype contiguous buckets, ONE
-                   reduce-scatter + all-gather per bucket over the
-                   per-device sub-ranges the sharded plan already pads to
-                   (marshalling on the interconnect; each rank reduces only
-                   its own 1/dp of every bucket instead of the whole
-                   payload, the bandwidth-optimal all-reduce decomposition)
+                   psum per bucket (marshalling on the interconnect)
     compress=True  int8 + error-feedback on the arena payload before psum
                    (collective bytes /4); only with grad_scheme="arena".
     """
@@ -166,18 +162,12 @@ def make_dp_train_step(api: ModelApi, optimizer: Optimizer,
                 synced[bucket] = out[:n].astype(buf.dtype)
                 new_err[bucket] = (chunks - q * scale[:, None]).reshape(-1)
             return engine_lib.unpack_traced(synced, layout), new_err
-        # reduce-scatter + all-gather over the per-device sub-ranges: the
-        # sharded plan pads every bucket to a multiple of dp_size, so each
-        # rank owns one contiguous 1/dp range, reduces ONLY that range
-        # (psum_scatter), and the all-gather reassembles the full bucket —
-        # same result and same bucket bytes as the all-reduce, but each
-        # link carries 1/dp of the payload per phase.
-        def rs_ag(buf):
-            part = jax.lax.psum_scatter(buf, axis, scatter_dimension=0,
-                                        tiled=True)
-            return jax.lax.all_gather(part, axis, axis=0, tiled=True)
-
-        synced = {b: rs_ag(buf) for b, buf in buffers.items()}
+        # one all-reduce per bucket.  XLA lowers an all-reduce to its own
+        # reduce-scatter + all-gather schedule, and its all-reduce combiner
+        # merges the bucket all-reduces with the loss's into one collective;
+        # an explicit psum_scatter + all_gather pair cannot be combined and
+        # costs two collectives per bucket.
+        synced = {b: jax.lax.psum(buf, axis) for b, buf in buffers.items()}
         return engine_lib.unpack_traced(synced, layout), error_state
 
     def step_fn(state, batch, error_state):
@@ -192,7 +182,6 @@ def make_dp_train_step(api: ModelApi, optimizer: Optimizer,
                      "step": state["step"] + 1}
         return new_state, {"loss": loss, "lr": lr}, error_state
 
-    from jax.experimental.shard_map import shard_map
     replicated = P()
     batch_spec = P(axis)
 
@@ -200,7 +189,7 @@ def make_dp_train_step(api: ModelApi, optimizer: Optimizer,
         return jax.tree_util.tree_map(lambda _: spec, tree)
 
     def wrapped(state, batch, error_state):
-        fn = shard_map(
+        fn = jax.shard_map(
             step_fn, mesh=mesh,
             in_specs=(shape_spec(state, replicated),
                       shape_spec(batch, batch_spec),
@@ -208,10 +197,10 @@ def make_dp_train_step(api: ModelApi, optimizer: Optimizer,
             out_specs=(shape_spec(state, replicated),
                        {"loss": replicated, "lr": replicated},
                        shape_spec(error_state, replicated)),
-            check_rep=False)
+            check_vma=False)
         return fn(state, batch, error_state)
 
-    return wrapped
+    return jax.jit(wrapped)
 
 
 def grad_arena_spec(dp_size: int = 1) -> TransferSpec:
@@ -252,7 +241,9 @@ def replicate_state(state: Any, num_devices: int) -> Any:
         return state
     from jax.sharding import NamedSharding, PartitionSpec
 
-    mesh = jax.make_mesh((num_devices,), ("data",))
+    from ..jaxenv import make_mesh
+
+    mesh = make_mesh((num_devices,), ("data",))
     target = NamedSharding(mesh, PartitionSpec())
     return jax.tree_util.tree_map(  # lint: allow=DC201 -- one-shot init placement
         lambda l: jax.device_put(l, target), state)

@@ -394,7 +394,9 @@ def data_sharding():
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
 
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    from ..jaxenv import make_mesh
+
+    mesh = make_mesh((jax.device_count(),), ("data",))
     return NamedSharding(mesh, PartitionSpec("data"))
 
 

@@ -120,3 +120,33 @@ assert out["w"].sharding == sh_b["w"]
 print("resharded ok")
 """)
     assert "resharded ok" in out
+
+
+def test_serve_dp4_matches_one_device():
+    """A server staging its params in a 4-way sharded arena serves every
+    request with no placement fallback, and its tokens equal those of a
+    one-device server over the same seeded tree and requests."""
+    out = _run_child(r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json
+from repro.launch.serve import build_parser, serve
+
+res = {}
+for dp in (4, 1):
+    done, server = serve(build_parser().parse_args(
+        ["--arch", "mamba2-1.3b", "--smoke", "--requests", "5", "--slots",
+         "3", "--max-new", "4", "--max-seq", "48", "--dp", str(dp)]))
+    res[dp] = {"tokens": {r.rid: r.tokens_out for r in done},
+               "states": sorted({r.state for r in done}),
+               "fallbacks": server.stats.policy_fallbacks,
+               "shards": server.policy.num_shards}
+print(json.dumps(res))
+""")
+    res = json.loads(out.strip().splitlines()[-1])
+    four, one = res["4"], res["1"]
+    assert (four["shards"], one["shards"]) == (4, 1)
+    assert four["fallbacks"] == one["fallbacks"] == 0
+    assert four["states"] == one["states"] == ["completed"]
+    assert len(four["tokens"]) == 5
+    assert four["tokens"] == one["tokens"]

@@ -118,8 +118,8 @@ def test_gather_tiles_sweep(n_tiles, dtype):
 def test_pack_tree_roundtrip():
     tree = {"a": jnp.arange(100, dtype=jnp.float32).reshape(10, 10),
             "b": {"c": jnp.full((3, 700), 2.0, jnp.float32)}}
-    packed, meta = mp_ops.pack_tree(tree)
-    out = mp_ops.unpack_tree(packed, meta)
+    packed, meta = mp_ops.pack_tree(tree, interpret=True)
+    out = mp_ops.unpack_tree(packed, meta, interpret=True)
     for x, y in zip(jax.tree_util.tree_leaves(tree),
                     jax.tree_util.tree_leaves(out)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
@@ -138,7 +138,8 @@ def test_ssd_kernel_vs_jnp_chunked(B, S, nh, hd, N, chunk):
     A = jnp.asarray(-np.abs(RNG.standard_normal(nh)) - 0.1, jnp.float32)
     Bm = jnp.asarray(RNG.standard_normal((B, S, N)), jnp.float32)
     Cm = jnp.asarray(RNG.standard_normal((B, S, N)), jnp.float32)
-    y1, s1 = ssd_ops.ssd_chunked_kernel(x, dt, A, Bm, Cm, chunk=chunk)
+    y1, s1 = ssd_ops.ssd_chunked_kernel(x, dt, A, Bm, Cm, chunk=chunk,
+                                        interpret=True)
     y2, s2 = ssd_chunked(x, dt, A, Bm, Cm, chunk=chunk)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
                                rtol=1e-4, atol=1e-4)

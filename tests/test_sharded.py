@@ -15,6 +15,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import (TransferSpec, clear_cache, declare, full_deepcopy,
                         plan, resolve_shards, shard_ranges, transfer_scheme)
+from repro.jaxenv import make_mesh
 from repro.scenarios import (derive_motion, iter_scenarios, motion_matches,
                              run_scenario)
 
@@ -30,7 +31,7 @@ def fresh_cache():
 
 @pytest.fixture()
 def sharding():
-    mesh = jax.make_mesh((K,), ("data",))
+    mesh = make_mesh((K,), ("data",))
     return NamedSharding(mesh, P("data"))
 
 
