@@ -52,6 +52,7 @@ replaced) before any gather of the same call.  See DESIGN.md §4/§7/§8.
 from __future__ import annotations
 
 import collections
+import dataclasses
 import time
 import weakref
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -413,6 +414,19 @@ def repack_traced(buffers: Buffers, layout: ArenaLayout, tree: Any) -> Buffers:
 FENCE_DEPTH = 8
 
 
+@dataclasses.dataclass
+class PackRecord:
+    """What one ``pack_host`` call did: the bytes it compared against
+    staging, copied into staging and skipped by identity, and the seconds
+    it waited on fences before rotating buffers.  A scheme books it into
+    its :class:`~repro.core.schemes.TransferLedger` once per call."""
+
+    compared_bytes: int = 0
+    staged_bytes: int = 0
+    identity_skipped_bytes: int = 0
+    fence_wait_s: float = 0.0
+
+
 def _drop_ready(fence: List[List[Any]]) -> None:
     """Forget fence groups whose values are all ready (or deleted):
     nothing they guard is in flight any more, and holding them keeps the
@@ -462,7 +476,7 @@ class ArenaEntry:
         self._last_leaf: List[Any] = [None] * layout.num_leaves
         self._recheck: set = set()          # buckets whose identity skip is off
         self.pack_host_calls = 0
-        self.fence_wait_s = 0.0             # accumulated; take_fence_wait()
+        self.last_pack = PackRecord()       # what the newest pack_host did
 
         def _unpack(buffers):
             return tuple(unpack_leaves(buffers, layout))
@@ -544,9 +558,11 @@ class ArenaEntry:
     def _wait_fence(self, bucket: str, buf_idx: int) -> None:
         fence = self._fences[bucket][buf_idx]
         if fence:
-            t0 = time.perf_counter()
-            jax.block_until_ready([v for grp in fence for v in grp])
-            self.fence_wait_s += time.perf_counter() - t0
+            with jax.profiler.TraceAnnotation(
+                    "ArenaEntry.pack_host.fence_wait", dtype=bucket):
+                t0 = time.perf_counter()
+                jax.block_until_ready([v for grp in fence for v in grp])
+                self.last_pack.fence_wait_s += time.perf_counter() - t0
             fence.clear()
         if _sanitizer._ACTIVE is not None:
             _sanitizer._ACTIVE.on_fence_wait(self, bucket, buf_idx)
@@ -556,10 +572,6 @@ class ArenaEntry:
             for fence in fences:
                 _drop_ready(fence)
 
-    def take_fence_wait(self) -> float:
-        s, self.fence_wait_s = self.fence_wait_s, 0.0
-        return s
-
     # -- host side ----------------------------------------------------------
     def pack_host(self, tree: Any, *, trust_identity: bool = False) -> Buffers:
         """Marshal into the persistent staging buffers and update version
@@ -568,44 +580,52 @@ class ArenaEntry:
         identical leaf object was packed last time (in-place mutators must
         ``mark_dirty``).  Buckets that change rotate to their spare buffer
         (waiting only that buffer's fence) and bump their version; the
-        shards a changed slot overlaps bump their shard versions.
+        shards a changed slot overlaps bump their shard versions.  What the
+        call compared, copied, skipped and waited is left in ``last_pack``.
         """
         leaves = jax.tree_util.tree_leaves(tree)
         if len(leaves) != self.layout.num_leaves:
             raise ValueError("tree does not match arena layout")
         self._drop_ready_fences()
+        record = self.last_pack = PackRecord()
         pending: Dict[int, np.ndarray] = {}
-        for i, (leaf, slot) in enumerate(zip(leaves, self.layout.slots)):
-            if slot.size == 0:
-                continue
-            recheck = slot.bucket in self._recheck
-            if (trust_identity and not recheck
-                    and self._last_leaf[i] is leaf):
-                if _sanitizer._ACTIVE is not None:
-                    # shadow memcmp: catches in-place mutation without
-                    # mark_dirty (DC306), exactly the check this fast
-                    # path elides
-                    _sanitizer._ACTIVE.on_identity_skip(self, slot, leaf)
-                continue
-            arr = np.asarray(leaf, dtype=slot.dtype).reshape(-1)
-            # the memcmp is the fingerprint: it costs one read pass over the
-            # leaf but is what lets shared entries keep exact versions (and
-            # lets unchanged repeat packs skip the write entirely).  A slot
-            # that was never packed is always dirty — no point comparing
-            # against the zero-initialised staging.
-            if self._last_leaf[i] is not None:
-                act = self._bufs[slot.bucket][self._active[slot.bucket]]
-                staged = act[slot.offset:slot.offset + slot.size]
-                # compare raw bytes, not values: NaN != NaN under value
-                # comparison, which would make any NaN-bearing bucket
-                # permanently dirty and silently defeat the delta path.
-                if np.array_equal(staged.view(np.uint8),
-                                  np.ascontiguousarray(arr).view(np.uint8)):
-                    self._last_leaf[i] = leaf
+        with jax.profiler.TraceAnnotation(
+                "ArenaEntry.pack_host.compare") as span:
+            for i, (leaf, slot) in enumerate(zip(leaves, self.layout.slots)):
+                if slot.size == 0:
                     continue
-            self._slot_vers[i] += 1
-            pending[i] = arr
-            self._last_leaf[i] = leaf
+                recheck = slot.bucket in self._recheck
+                if (trust_identity and not recheck
+                        and self._last_leaf[i] is leaf):
+                    record.identity_skipped_bytes += \
+                        slot.size * np.dtype(slot.bucket).itemsize
+                    if _sanitizer._ACTIVE is not None:
+                        # shadow memcmp: catches in-place mutation without
+                        # mark_dirty (DC306), exactly the check this fast
+                        # path elides
+                        _sanitizer._ACTIVE.on_identity_skip(self, slot, leaf)
+                    continue
+                arr = np.asarray(leaf, dtype=slot.dtype).reshape(-1)
+                # the memcmp is the fingerprint: it costs one read pass over
+                # the leaf but is what lets shared entries keep exact
+                # versions (and lets unchanged repeat packs skip the write
+                # entirely).  A slot that was never packed is always dirty —
+                # no point comparing against the zero-initialised staging.
+                if self._last_leaf[i] is not None:
+                    record.compared_bytes += arr.nbytes
+                    act = self._bufs[slot.bucket][self._active[slot.bucket]]
+                    staged = act[slot.offset:slot.offset + slot.size]
+                    # compare raw bytes, not values: NaN != NaN under value
+                    # comparison, which would make any NaN-bearing bucket
+                    # permanently dirty and silently defeat the delta path.
+                    if np.array_equal(staged.view(np.uint8),
+                                      np.ascontiguousarray(arr).view(np.uint8)):
+                        self._last_leaf[i] = leaf
+                        continue
+                self._slot_vers[i] += 1
+                pending[i] = arr
+                self._last_leaf[i] = leaf
+            span.set_metadata(bytes=record.compared_bytes)
         dirty = {self.layout.slots[i].bucket for i in pending}
         for b in dirty:
             tgt = 1 - self._active[b]
@@ -614,15 +634,21 @@ class ArenaEntry:
                 _sanitizer._ACTIVE.on_staging_write(self, b, tgt)
             buf = self._bufs[b][tgt]
             held = self._buf_slot_vers[b][tgt]
-            for lj, si in enumerate(self._bucket_slots[b]):
-                if held[lj] < self._slot_vers[si]:
-                    slot = self.layout.slots[si]
-                    arr = pending.get(si)
-                    if arr is None:
-                        arr = np.asarray(leaves[si],
-                                         dtype=slot.dtype).reshape(-1)
-                    buf[slot.offset:slot.offset + slot.size] = arr
-                    held[lj] = self._slot_vers[si]
+            with jax.profiler.TraceAnnotation(
+                    "ArenaEntry.pack_host.copy", dtype=b) as span:
+                staged_bytes = 0
+                for lj, si in enumerate(self._bucket_slots[b]):
+                    if held[lj] < self._slot_vers[si]:
+                        slot = self.layout.slots[si]
+                        arr = pending.get(si)
+                        if arr is None:
+                            arr = np.asarray(leaves[si],
+                                             dtype=slot.dtype).reshape(-1)
+                        buf[slot.offset:slot.offset + slot.size] = arr
+                        staged_bytes += arr.nbytes
+                        held[lj] = self._slot_vers[si]
+                span.set_metadata(bytes=staged_bytes)
+            record.staged_bytes += staged_bytes
             self._active[b] = tgt
             if _sanitizer._ACTIVE is not None:
                 _sanitizer._ACTIVE.on_rotate(self, b, tgt)

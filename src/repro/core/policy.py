@@ -647,7 +647,8 @@ class TransferProgram:
         enqueues: Dict[str, int] = {}
         # the enqueue half: the sanitizer (when active) flags any blocking
         # barrier issued inside it (DC304 — the one-sync-per-pass contract)
-        with _sanitizer.enqueue_half():
+        with jax.profiler.TraceAnnotation("TransferProgram.begin"), \
+                _sanitizer.enqueue_half():
             for key, region in self.regions.items():
                 sub = [leaves[i] for i in region.indices]
                 pending, finish = self._schemes[key].begin_pass(sub)
@@ -661,12 +662,13 @@ class TransferProgram:
         """The finish stage: per-region bookkeeping (ledgers, retained
         buckets, staging fences) + tree assembly, after the barrier."""
         out = list(leaves)
-        for region, finish in finishes:
-            for i, leaf in zip(region.indices,
-                               jax.tree_util.tree_leaves(
-                                   finish(), is_leaf=_is_opaque_leaf)):
-                out[i] = leaf
-        return jax.tree_util.tree_unflatten(self.treedef, out)
+        with jax.profiler.TraceAnnotation("TransferProgram.finish"):
+            for region, finish in finishes:
+                for i, leaf in zip(region.indices,
+                                   jax.tree_util.tree_leaves(
+                                       finish(), is_leaf=_is_opaque_leaf)):
+                    out[i] = leaf
+            return jax.tree_util.tree_unflatten(self.treedef, out)
 
     def to_device(self, tree: Any) -> Any:
         """One blocking program pass: enqueue all regions' buckets, ONE
@@ -675,19 +677,21 @@ class TransferProgram:
         Each region moves its leaves under its own spec (delta regions ship
         only dirty buckets/shards; uvm regions wrap lazily and fault later,
         contributing zero enqueues here)."""
-        leaves, pending_all, finishes, enqueues = self._begin(tree)
-        t0 = time.perf_counter()
-        if _sanitizer._ACTIVE is not None:
-            _sanitizer._ACTIVE.on_sync("TransferProgram.to_device")
-        jax.block_until_ready(pending_all)
-        t1 = time.perf_counter()
-        out = self._finish(leaves, finishes)
-        t2 = time.perf_counter()
-        self.last_stats = ProgramStats(enqueues, 1, t1 - t0,
-                                       finish_s=t2 - t1)
-        if _sanitizer._ACTIVE is not None:
-            _sanitizer._ACTIVE.on_pass_stats(self.last_stats)
-        return out
+        with jax.profiler.TraceAnnotation("TransferProgram.to_device"):
+            leaves, pending_all, finishes, enqueues = self._begin(tree)
+            t0 = time.perf_counter()
+            if _sanitizer._ACTIVE is not None:
+                _sanitizer._ACTIVE.on_sync("TransferProgram.to_device")
+            with jax.profiler.TraceAnnotation("TransferProgram.barrier"):
+                jax.block_until_ready(pending_all)
+            t1 = time.perf_counter()
+            out = self._finish(leaves, finishes)
+            t2 = time.perf_counter()
+            self.last_stats = ProgramStats(enqueues, 1, t1 - t0,
+                                           finish_s=t2 - t1)
+            if _sanitizer._ACTIVE is not None:
+                _sanitizer._ACTIVE.on_pass_stats(self.last_stats)
+            return out
 
     def to_device_async(self, tree: Any) -> ProgramFuture:
         """The pipelined pass: pack + enqueue every region NOW (on the

@@ -73,6 +73,13 @@ class TransferLedger:
     per-device equality ``h2d + skipped == full sharded motion`` holds on
     EVERY device of a sharded delta transfer; an unsharded path records
     everything under its one device.
+
+    Host-side staging work, booked from each ``ArenaEntry.pack_host`` call's
+    :class:`~repro.core.engine.PackRecord`: ``compared_bytes`` (memcmp'd
+    against staging), ``staged_bytes`` (memcpy'd into staging) and
+    ``identity_skipped_bytes`` (skipped by ``trust_identity``).  Per pack,
+    compared + identity-skipped + never-packed-before bytes equal the
+    region's non-empty leaf bytes.  The pack's fence wait goes to ``sync_s``.
     """
 
     h2d_bytes: int = 0
@@ -86,6 +93,9 @@ class TransferLedger:
     finish_s: float = 0.0    # post-barrier bookkeeping on the caller's thread
     skipped_bytes: int = 0   # delta: bytes proven unchanged, not re-shipped
     delta_calls: int = 0     # transfer passes that skipped >=1 clean bucket
+    compared_bytes: int = 0  # pack_host: memcmp'd against staging
+    staged_bytes: int = 0    # pack_host: memcpy'd into staging
+    identity_skipped_bytes: int = 0   # pack_host: skipped by trust_identity
     h2d_bytes_by_device: Dict[str, int] = dataclasses.field(default_factory=dict)
     h2d_calls_by_device: Dict[str, int] = dataclasses.field(default_factory=dict)
     skipped_bytes_by_device: Dict[str, int] = dataclasses.field(default_factory=dict)
@@ -110,6 +120,15 @@ class TransferLedger:
             key = self._device_key(device)
             self.skipped_bytes_by_device[key] = \
                 self.skipped_bytes_by_device.get(key, 0) + int(nbytes)
+
+    def record_pack(self, record: engine_lib.PackRecord) -> None:
+        """Book one ``pack_host`` call: its byte counts, and its fence wait
+        as time the caller was blocked (``sync_s``)."""
+        self.compared_bytes += record.compared_bytes
+        self.staged_bytes += record.staged_bytes
+        self.identity_skipped_bytes += record.identity_skipped_bytes
+        if record.fence_wait_s:
+            self.record_wall(0.0, record.fence_wait_s)
 
     def record_d2h(self, nbytes: int) -> None:
         self.d2h_bytes += int(nbytes)
@@ -152,6 +171,9 @@ class TransferLedger:
             self.d2h_calls += o.d2h_calls
             self.skipped_bytes += o.skipped_bytes
             self.delta_calls += o.delta_calls
+            self.compared_bytes += o.compared_bytes
+            self.staged_bytes += o.staged_bytes
+            self.identity_skipped_bytes += o.identity_skipped_bytes
             self.record_wall(o.enqueue_s, o.sync_s)
             self.record_overlap(o.overlap_s)
             self.record_finish(o.finish_s)
@@ -168,6 +190,8 @@ class TransferLedger:
         self.wall_s = self.enqueue_s = self.sync_s = 0.0
         self.overlap_s = self.finish_s = 0.0
         self.skipped_bytes = self.delta_calls = 0
+        self.compared_bytes = self.staged_bytes = 0
+        self.identity_skipped_bytes = 0
         self.h2d_bytes_by_device.clear()
         self.h2d_calls_by_device.clear()
         self.skipped_bytes_by_device.clear()
@@ -351,7 +375,8 @@ class TransferScheme:
         if not xs:
             return []
         t0 = time.perf_counter()
-        ys = [jax.device_put(x, self.target) for x in xs]
+        with jax.profiler.TraceAnnotation("TransferScheme.device_put"):
+            ys = [jax.device_put(x, self.target) for x in xs]
         t1 = time.perf_counter()
         if sync:
             if _sanitizer._ACTIVE is not None:
@@ -579,8 +604,7 @@ class MarshalScheme(TransferScheme):
             return self._to_device_delta(tree)
         if self.staging == "double_buffered":
             return self._to_device_pipelined(tree)
-        entry = self._entry_for(tree)
-        buffers = entry.pack_host(tree)
+        entry, buffers = self._pack(tree)
         names = list(buffers)
         dev = self._put_batch([buffers[b] for b in names])
         out = entry.unpack(dict(zip(names, dev)))
@@ -590,10 +614,12 @@ class MarshalScheme(TransferScheme):
         # live device value still reads staging when we return.
         return jax.block_until_ready(out)
 
-    def _record_fence_wait(self, entry) -> None:
-        fence_s = entry.take_fence_wait()
-        if fence_s:
-            self.ledger.record_wall(0.0, fence_s)
+    def _pack(self, tree, trust_identity: bool = False):
+        """``pack_host`` on this tree's entry, booked into the ledger."""
+        entry = self._entry_for(tree)
+        buffers = entry.pack_host(tree, trust_identity=trust_identity)
+        self.ledger.record_pack(entry.last_pack)
+        return entry, buffers
 
     # -- sanitizer hooks (DESIGN.md §13.3) -----------------------------------
     @staticmethod
@@ -615,9 +641,7 @@ class MarshalScheme(TransferScheme):
 
     # -- double-buffered full transfers (the §7 pipeline, no delta skip) -----
     def _begin_pipelined(self, tree):
-        entry = self._entry_for(tree)
-        buffers = entry.pack_host(tree)
-        self._record_fence_wait(entry)
+        entry, buffers = self._pack(tree)
         names = list(buffers)
         dev = self._put_batch([buffers[b] for b in names], sync=False)
         self._san_enqueued(entry, buffers, names)
@@ -642,10 +666,7 @@ class MarshalScheme(TransferScheme):
 
     # -- delta: dirty-bucket incremental transfers ---------------------------
     def _begin_delta(self, tree):
-        entry = self._entry_for(tree)
-        buffers = entry.pack_host(tree, trust_identity=True)
-        # fence waits done inside pack_host are this path's sync cost
-        self._record_fence_wait(entry)
+        entry, buffers = self._pack(tree, trust_identity=True)
         retained = self._delta_state.retained.setdefault(entry, {})
         names = list(buffers)
         bucket_bytes = entry.layout.bucket_bytes()
@@ -737,16 +758,18 @@ class MarshalScheme(TransferScheme):
         bsh = self._bucket_sharding()
         plan: Dict[str, list] = {}
         t0 = time.perf_counter()
-        for b, buf in buffers.items():
-            n = int(buf.shape[0])
-            shards = []
-            for dev, idx in bsh.devices_indices_map((n,)).items():
-                sl = idx[0]
-                lo = 0 if sl.start is None else int(sl.start)
-                hi = n if sl.stop is None else int(sl.stop)
-                shards.append((lo, hi, dev, jax.device_put(buf[lo:hi], dev)))
-            shards.sort(key=lambda s: s[0])
-            plan[b] = shards
+        with jax.profiler.TraceAnnotation("TransferScheme.device_put"):
+            for b, buf in buffers.items():
+                n = int(buf.shape[0])
+                shards = []
+                for dev, idx in bsh.devices_indices_map((n,)).items():
+                    sl = idx[0]
+                    lo = 0 if sl.start is None else int(sl.start)
+                    hi = n if sl.stop is None else int(sl.stop)
+                    shards.append((lo, hi, dev,
+                                   jax.device_put(buf[lo:hi], dev)))
+                shards.sort(key=lambda s: s[0])
+                plan[b] = shards
         self.ledger.record_wall(time.perf_counter() - t0, 0.0)
         return plan
 
@@ -764,9 +787,7 @@ class MarshalScheme(TransferScheme):
         return out
 
     def _begin_sharded(self, tree):
-        entry = self._entry_for(tree)
-        buffers = entry.pack_host(tree)
-        self._record_fence_wait(entry)
+        entry, buffers = self._pack(tree)
         plan = self._enqueue_sharded(buffers)
         pending = [s[3] for ss in plan.values() for s in ss]
         self._san_enqueued(entry, {}, list(buffers))
@@ -789,8 +810,7 @@ class MarshalScheme(TransferScheme):
         return pending, finish
 
     def _to_device_sharded(self, tree):
-        entry = self._entry_for(tree)
-        buffers = entry.pack_host(tree)
+        entry, buffers = self._pack(tree)
         dev_bufs = self._put_sharded(buffers)
         out = entry.unpack(dev_bufs)
         # same sync-before-rewrite discipline as the single-device path:
@@ -817,9 +837,7 @@ class MarshalScheme(TransferScheme):
         delta path: staging safety is the per-buffer fence discipline plus
         range disjointness (a clean shard's byte range is never rewritten
         while its retained array is live — see engine.py)."""
-        entry = self._entry_for(tree)
-        buffers = entry.pack_host(tree, trust_identity=True)
-        self._record_fence_wait(entry)
+        entry, buffers = self._pack(tree, trust_identity=True)
         retained = self._delta_state.retained.setdefault(entry, {})
         names = list(buffers)
         order = self._shard_device_order()
@@ -850,8 +868,9 @@ class MarshalScheme(TransferScheme):
 
                 return [], finish_memo
         t0 = time.perf_counter()
-        new = [(b, s, dev, jax.device_put(buffers[b][lo:hi], dev))
-               for b, s, lo, hi, dev in ships]
+        with jax.profiler.TraceAnnotation("TransferScheme.device_put"):
+            new = [(b, s, dev, jax.device_put(buffers[b][lo:hi], dev))
+                   for b, s, lo, hi, dev in ships]
         self.ledger.record_wall(time.perf_counter() - t0, 0.0)
         shipped_buckets = sorted({s[0] for s in ships})
         self._san_enqueued(entry, {}, shipped_buckets)
