@@ -21,12 +21,17 @@ have not changed (delta transfers):
       - TWO host staging buffers per dtype bucket (double buffering): a
         rewrite rotates to the other buffer and waits only that buffer's
         fence, so packing call N+1 can overlap the in-flight DMA of call N;
-      - per-bucket monotone **version counters**: ``pack_host`` memcmp's
-        each leaf against the staged copy and bumps a bucket's version only
-        when bytes actually changed (``trust_identity=True`` additionally
-        skips the memcmp when the identical leaf *object* was packed last
-        time — callers that mutate leaves in place must then call
-        :meth:`ArenaEntry.mark_dirty` / :meth:`ArenaEntry.bump_version`);
+      - per-bucket monotone **version counters**: ``pack_host`` compares
+        each leaf's bytes against the staged copy and bumps a bucket's
+        version only when bytes actually changed (``trust_identity=True``
+        additionally skips the compare when the identical leaf *object* was
+        packed last time — callers that mutate leaves in place must then
+        call :meth:`ArenaEntry.mark_dirty` / :meth:`ArenaEntry.bump_version`).
+        The compare (:func:`_bytes_equal`) runs in machine words, a chunk of
+        ``COMPARE_CHUNK`` words at a time into one small bool buffer that
+        the call reuses for every leaf, and stops at the first chunk that
+        differs; ``PackRecord.compare_unread_bytes`` and ``compare_exits``
+        count what such early exits left unread;
       - per-(bucket, shard) version counters (``shard_versions``) for
         sharded layouts: a changed slot bumps exactly the shards whose
         element ranges it overlaps, so a per-device delta transfer
@@ -413,18 +418,59 @@ def repack_traced(buffers: Buffers, layout: ArenaLayout, tree: Any) -> Buffers:
 # force-waited so a long clean streak cannot pin unbounded device values.
 FENCE_DEPTH = 8
 
+# words per step of a leaf's byte compare: the bool buffer that takes one
+# step's results is this long (128 KiB), and a mismatch ends the compare
+# after the step it lies in.
+COMPARE_CHUNK = 1 << 17
+_WORDS = tuple(map(np.dtype, ("u8", "u4", "u2", "u1")))
+
+
+def _bytes_equal(a: np.ndarray, b: np.ndarray,
+                 scratch: np.ndarray) -> Tuple[bool, int]:
+    """Whether two C-contiguous 1-D arrays of the same byte length hold
+    the same bytes, and how many bytes of each were read to tell.
+
+    The bytes are compared as the widest unsigned words that both start
+    addresses are aligned to (a tail shorter than a word in the next
+    narrower ones, down to single bytes), ``len(scratch)`` words at a time
+    into ``scratch``; the first step that finds a difference ends the
+    compare.  Bitwise, not by value: NaNs with equal payloads compare
+    equal, +0.0 and -0.0 do not.  ``scratch`` is a bool buffer the caller
+    reuses, so no leaf-sized temporary is made."""
+    a, b = a.view(np.uint8), b.view(np.uint8)
+    n = a.nbytes
+    align = a.ctypes.data | b.ctypes.data
+    step = len(scratch)
+    pos = 0
+    for word in _WORDS:
+        w = word.itemsize
+        if align % w:
+            continue
+        end = pos + (n - pos) // w * w
+        x, y = a[pos:end].view(word), b[pos:end].view(word)
+        for i in range(0, x.size, step):
+            j = min(i + step, x.size)
+            if not np.equal(x[i:j], y[i:j], out=scratch[:j - i]).all():
+                return False, pos + j * w
+        pos = end
+    return True, n
+
 
 @dataclasses.dataclass
 class PackRecord:
     """What one ``pack_host`` call did: the bytes it compared against
-    staging, copied into staging and skipped by identity, and the seconds
-    it waited on fences before rotating buffers.  A scheme books it into
-    its :class:`~repro.core.schemes.TransferLedger` once per call."""
+    staging, copied into staging and skipped by identity, the seconds it
+    waited on fences before rotating buffers, and how many compares a
+    mismatch ended early and the bytes of them left unread (part of
+    ``compared_bytes``).  A scheme books it into its
+    :class:`~repro.core.schemes.TransferLedger` once per call."""
 
     compared_bytes: int = 0
     staged_bytes: int = 0
     identity_skipped_bytes: int = 0
     fence_wait_s: float = 0.0
+    compare_unread_bytes: int = 0
+    compare_exits: int = 0
 
 
 def _drop_ready(fence: List[List[Any]]) -> None:
@@ -575,9 +621,11 @@ class ArenaEntry:
     # -- host side ----------------------------------------------------------
     def pack_host(self, tree: Any, *, trust_identity: bool = False) -> Buffers:
         """Marshal into the persistent staging buffers and update version
-        counters.  Per leaf: skip when the staged bytes already match
-        (memcmp); with ``trust_identity`` also skip the memcmp when the
-        identical leaf object was packed last time (in-place mutators must
+        counters.  Per leaf: skip when the staged bytes already match (a
+        word-wise compare in chunks into one bool buffer reused across the
+        call, ended by the first chunk that differs: :func:`_bytes_equal`);
+        with ``trust_identity`` also skip the compare when the identical
+        leaf object was packed last time (in-place mutators must
         ``mark_dirty``).  Buckets that change rotate to their spare buffer
         (waiting only that buffer's fence) and bump their version; the
         shards a changed slot overlaps bump their shard versions.  What the
@@ -588,6 +636,7 @@ class ArenaEntry:
             raise ValueError("tree does not match arena layout")
         self._drop_ready_fences()
         record = self.last_pack = PackRecord()
+        scratch = np.empty(COMPARE_CHUNK, np.bool_)
         pending: Dict[int, np.ndarray] = {}
         with jax.profiler.TraceAnnotation(
                 "ArenaEntry.pack_host.compare") as span:
@@ -606,11 +655,13 @@ class ArenaEntry:
                         _sanitizer._ACTIVE.on_identity_skip(self, slot, leaf)
                     continue
                 arr = np.asarray(leaf, dtype=slot.dtype).reshape(-1)
-                # the memcmp is the fingerprint: it costs one read pass over
-                # the leaf but is what lets shared entries keep exact
-                # versions (and lets unchanged repeat packs skip the write
-                # entirely).  A slot that was never packed is always dirty —
-                # no point comparing against the zero-initialised staging.
+                # the byte compare is the fingerprint: it costs at most one
+                # read pass over the leaf (a changed leaf stops at the chunk
+                # of its first difference) but is what lets shared entries
+                # keep exact versions (and lets unchanged repeat packs skip
+                # the write entirely).  A slot that was never packed is
+                # always dirty — no point comparing against the
+                # zero-initialised staging.
                 if self._last_leaf[i] is not None:
                     record.compared_bytes += arr.nbytes
                     act = self._bufs[slot.bucket][self._active[slot.bucket]]
@@ -618,14 +669,18 @@ class ArenaEntry:
                     # compare raw bytes, not values: NaN != NaN under value
                     # comparison, which would make any NaN-bearing bucket
                     # permanently dirty and silently defeat the delta path.
-                    if np.array_equal(staged.view(np.uint8),
-                                      np.ascontiguousarray(arr).view(np.uint8)):
+                    equal, read = _bytes_equal(
+                        staged, np.ascontiguousarray(arr), scratch)
+                    if equal:
                         self._last_leaf[i] = leaf
                         continue
+                    record.compare_unread_bytes += arr.nbytes - read
+                    record.compare_exits += 1
                 self._slot_vers[i] += 1
                 pending[i] = arr
                 self._last_leaf[i] = leaf
-            span.set_metadata(bytes=record.compared_bytes)
+            span.set_metadata(bytes=record.compared_bytes,
+                              unread_bytes=record.compare_unread_bytes)
         dirty = {self.layout.slots[i].bucket for i in pending}
         for b in dirty:
             tgt = 1 - self._active[b]

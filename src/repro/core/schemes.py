@@ -75,8 +75,10 @@ class TransferLedger:
     everything under its one device.
 
     Host-side staging work, booked from each ``ArenaEntry.pack_host`` call's
-    :class:`~repro.core.engine.PackRecord`: ``compared_bytes`` (memcmp'd
-    against staging), ``staged_bytes`` (memcpy'd into staging) and
+    :class:`~repro.core.engine.PackRecord`: ``compared_bytes`` (submitted to
+    the compare against staging), ``compare_unread_bytes`` (the part of
+    those a mismatch left unread) and ``compare_exits`` (compares a
+    mismatch ended), ``staged_bytes`` (memcpy'd into staging) and
     ``identity_skipped_bytes`` (skipped by ``trust_identity``).  Per pack,
     compared + identity-skipped + never-packed-before bytes equal the
     region's non-empty leaf bytes.  The pack's fence wait goes to ``sync_s``.
@@ -93,7 +95,9 @@ class TransferLedger:
     finish_s: float = 0.0    # post-barrier bookkeeping on the caller's thread
     skipped_bytes: int = 0   # delta: bytes proven unchanged, not re-shipped
     delta_calls: int = 0     # transfer passes that skipped >=1 clean bucket
-    compared_bytes: int = 0  # pack_host: memcmp'd against staging
+    compared_bytes: int = 0  # pack_host: submitted to the staging compare
+    compare_unread_bytes: int = 0  # ... of which a mismatch left unread
+    compare_exits: int = 0   # pack_host: compares a mismatch ended early
     staged_bytes: int = 0    # pack_host: memcpy'd into staging
     identity_skipped_bytes: int = 0   # pack_host: skipped by trust_identity
     h2d_bytes_by_device: Dict[str, int] = dataclasses.field(default_factory=dict)
@@ -125,6 +129,8 @@ class TransferLedger:
         """Book one ``pack_host`` call: its byte counts, and its fence wait
         as time the caller was blocked (``sync_s``)."""
         self.compared_bytes += record.compared_bytes
+        self.compare_unread_bytes += record.compare_unread_bytes
+        self.compare_exits += record.compare_exits
         self.staged_bytes += record.staged_bytes
         self.identity_skipped_bytes += record.identity_skipped_bytes
         if record.fence_wait_s:
@@ -172,6 +178,8 @@ class TransferLedger:
             self.skipped_bytes += o.skipped_bytes
             self.delta_calls += o.delta_calls
             self.compared_bytes += o.compared_bytes
+            self.compare_unread_bytes += o.compare_unread_bytes
+            self.compare_exits += o.compare_exits
             self.staged_bytes += o.staged_bytes
             self.identity_skipped_bytes += o.identity_skipped_bytes
             self.record_wall(o.enqueue_s, o.sync_s)
@@ -191,6 +199,7 @@ class TransferLedger:
         self.overlap_s = self.finish_s = 0.0
         self.skipped_bytes = self.delta_calls = 0
         self.compared_bytes = self.staged_bytes = 0
+        self.compare_unread_bytes = self.compare_exits = 0
         self.identity_skipped_bytes = 0
         self.h2d_bytes_by_device.clear()
         self.h2d_calls_by_device.clear()

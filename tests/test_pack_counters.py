@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import TransferSession, transfer_scheme
+from repro.core import engine as engine_lib
 from repro.core.engine import PackRecord
 from repro.core.schemes import TransferLedger
 
@@ -126,6 +127,55 @@ def test_the_record_of_one_pack_is_booked_once():
     assert _counts(merged) == (8, 7, 7) and merged.sync_s == 0.25
     ledger.reset()
     assert _counts(ledger) == (0, 0, 0) and ledger.wall_s == 0.0
+
+
+def _exits(ledger):
+    return ledger.compare_exits, ledger.compare_unread_bytes
+
+
+def test_compare_exits_and_unread_bytes_are_booked_merged_and_reset():
+    ledger = TransferLedger()
+    ledger.record_pack(PackRecord(compared_bytes=9, compare_unread_bytes=6,
+                                  compare_exits=2))
+    assert _exits(ledger) == (2, 6) and ledger.compared_bytes == 9
+    other = TransferLedger()
+    other.record_pack(PackRecord(compared_bytes=4, compare_unread_bytes=1,
+                                 compare_exits=1))
+    assert _exits(TransferLedger().merge(ledger, other)) == (3, 7)
+    ledger.reset()
+    assert _exits(ledger) == (0, 0)
+
+
+def test_a_mismatch_ends_the_compare_and_its_unread_bytes_are_booked(
+        monkeypatch):
+    # 4 words (32 B) a chunk: k's 512 B are 16 chunks
+    monkeypatch.setattr(engine_lib, "COMPARE_CHUNK", 4)
+    session = TransferSession()
+    tree = _tree()
+    program = session.compile(tree, POLICY)
+    program.to_device(tree)
+    tree["cache"]["k"][1, 0, 0] += 1.0      # byte 128 of k: its fifth chunk
+    program.mark_dirty(tree, "cache")
+    params, cache, out = _pass(program, tree)
+    # what was submitted to the compare is unchanged by the early exit
+    assert params == (PARAMS_BYTES, 0, 0)
+    assert cache == (CACHE_BYTES, K + V, 0)
+    assert _exits(program.region_ledger(PARAMS)) == (0, 0)
+    assert _exits(program.region_ledger(CACHE)) == (1, K - 5 * 32)
+    # booked once: the ledger holds the one pack's record
+    entry = program.scheme(CACHE)._entry
+    assert _exits(program.region_ledger(CACHE)) == (
+        entry.last_pack.compare_exits, entry.last_pack.compare_unread_bytes)
+    assert _exits(program.merged_ledger()) == (1, K - 5 * 32)
+    np.testing.assert_array_equal(np.asarray(out["cache"]["k"]),
+                                  tree["cache"]["k"])
+    # an unchanged repeat, flagged: everything is read, nothing exits
+    program.mark_dirty(tree, "cache")
+    params, cache, _ = _pass(program, tree)
+    assert cache == (CACHE_BYTES, 0, 0)
+    assert _exits(program.merged_ledger()) == (0, 0)
+    program.clear()
+    assert _exits(program.merged_ledger()) == (0, 0)
 
 
 class _SlowFence:
