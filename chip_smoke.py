@@ -5,6 +5,7 @@ widths of mamba2-1.3b (48 layers, d_model 2048, ssm_state 128, vocab
 
     python chip_smoke.py              # one chip: phases (a) and (b)
     python chip_smoke.py --chips 4    # four chips: the @dp4 path only
+    python chip_smoke.py --arch moonlight-16b-a3b-s9   # one chip: phase (m)
 
 Everything runs in this one process, which holds the chip.
 
@@ -25,10 +26,31 @@ Everything runs in this one process, which holds the chip.
 arena plan and the round trip, serves the same requests at ``--dp 4``, and
 compares their tokens with a one-device staging of the same tree.
 
+``--arch moonlight-16b-a3b-s9`` runs phase (m) instead, at the widths of
+``bench/configs/moonlight-16b-a3b-s9.json`` (Moonlight-16B-A3B, one
+chip's share: 9 layers, 8 of 64 experts, a 20,480-row vocabulary) with
+the benchmark's seeded bf16 weights (``bench/families/moe.py``):
+
+(m) A 16-slot, 8,192-position ServeState staged through
+    ``serve_transfer_policy(1)``; an 8,000-token prompt (ids from the
+    vocabulary slice) prefilled into slot 3 and installed as the server
+    installs a refill; slot 3's rows read back to the host as a saved
+    session, written into slot 11 of the host tree, ``mark_dirty`` and
+    ``to_device`` (the resume op of ``moonlight-16b-a3b-s9.resume-8k``);
+    the resumed rows on the device equal the saved ones byte for byte;
+    16 tokens decoded from slot 11.  The logits of the prefill and of
+    each decode step are compared with the plain reference
+    (``bench/reference/deepseek_v3.py``, float32, highest precision, in
+    query blocks) over prompt + tokens, in bf16 as the server runs, and
+    again with the program at float32 (prefill and decode on a one-slot
+    cache).
+
 Any failed check raises and the script exits 1.  Without a TPU (for
 example under ``JAX_PLATFORMS=cpu``) it exits 1 naming the platform it
 found.  The last line of standard output, on success only, is
-``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``;
+phase (m) adds ``"moonlight"``: the logit errors against the reference
+and the peak device memory.
 """
 from __future__ import annotations
 
@@ -62,6 +84,23 @@ SERVE_ARGV = ["--arch", ARCH, "--slots", str(SLOTS), "--requests",
 TOL_F32_FULL_DEPTH = 1e-2
 TOL_BF16_DEPTH4 = 5e-2
 BF16_CHECK_LAYERS = 4
+
+MOONLIGHT = "moonlight-16b-a3b-s9"
+ML_SLOTS, ML_MAX_SEQ, ML_PROMPT, ML_DECODE = 16, 8192, 8000, 16
+ML_FROM, ML_TO = 3, 11          # the slot prefilled, the slot resumed into
+ML_BLOCK_Q = 512                # the reference's query blocks
+# Phase (m), logits of each compared position (the prompt's last, then the
+# 16 decoded) against the reference, as ||a - b|| / ||b||:
+#  * float32 program, highest matmul precision: the two differ by summation
+#    order only, 1.2e-6 at d_model 1024 and 9 layers on the CPU backend;
+#    rounding attention's inputs to bf16 moves them by ~1e-2 (8e-2 at tiny
+#    widths), the experts' by 1e-3.  Bound 1e-3 on every position.
+#  * bf16 as the server runs: roundoff gives ~1e-2 a position at d_model
+#    1024 on the CPU backend, and where bf16 flips an expert choice near a
+#    tie one position reads ~0.1; decoding the same tokens after another
+#    session reads 1.1-1.5.  Bound 0.1 on the median of the 17 positions.
+TOL_ML_F32 = 1e-3
+TOL_ML_BF16_MEDIAN = 0.1
 
 
 class SmokeFailure(AssertionError):
@@ -372,12 +411,158 @@ def phase_dp4(api, devices, clock) -> None:
                f"{peak_bytes(devices)}")
 
 
-def run(chips: int) -> dict:
+def _rel_errors(got, want):
+    import numpy as np
+
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    check(bool(np.isfinite(got).all() and np.isfinite(want).all()),
+          "non-finite logits")
+    return [float(np.linalg.norm(a - b) / np.linalg.norm(b))
+            for a, b in zip(got, want)]
+
+
+def _decode_from(api, params, cache, slot, tokens):
+    """Decode ``tokens`` one at a time from ``slot`` of ``cache`` (the other
+    slots decode zeros); the slot's logits of each step."""
+    import jax
+    import jax.numpy as jnp
+
+    decode = jax.jit(api.decode_step)
+    n = cache["pos"].shape[0]
+    out = []
+    for tok in tokens:
+        toks = jnp.zeros((n, 1), jnp.int32).at[slot, 0].set(int(tok))
+        logits, cache = decode(params, toks, cache)
+        out.append(logits[slot, 0])
+    return jax.device_get(out)
+
+
+def phase_moonlight(devices, clock, cfg=None) -> dict:
+    """Phase (m): resume an 8k-token latent session into a slot of the
+    s9 ServeState and decode from it, against the reference.  ``cfg``:
+    the configuration as a dict (default: the s9 file)."""
+    import json
+    from pathlib import Path
+
+    import jax
+    import numpy as np
+    from bench import harness, weights
+    from bench.model import check_params, model_api
+    from bench.reference import deepseek_v3 as ref
+    from repro.core.engine import TransferSession
+    from repro.launch.serve import SEED
+    from repro.runtime import serve_transfer_policy
+    from repro.runtime.serve import serve_state
+
+    if cfg is None:
+        cfg = json.loads((Path(ROOT) / "bench" / "configs"
+                          / f"{MOONLIGHT}.json").read_text())
+    family = harness.load_family(Path(ROOT), cfg["family"])
+    api = model_api(cfg)
+    params = weights.make_params(family, cfg, SEED)
+    check_params(api, params)
+    rng = np.random.default_rng(SEED)
+    ids = rng.integers(0, cfg["vocab_size"], ML_PROMPT + ML_DECODE,
+                       dtype=np.int32)
+    prompt, follow = ids[:ML_PROMPT], ids[ML_PROMPT:]
+
+    # the ServeState through the serving policy
+    # writable: the resume op writes a slot of it in place
+    host = jax.tree_util.tree_map(
+        np.array, serve_state(api, params, ML_SLOTS, ML_MAX_SEQ))
+    del params
+    session = TransferSession()
+    program = session.compile(host, serve_transfer_policy(1))
+    dev = jax.block_until_ready(program.to_device(host))
+    nbytes = sum(int(np.asarray(leaf).nbytes) for leaf in jax_leaves(host))
+    say("m", f"{cfg['name']}: ServeState {nbytes} B staged under "
+             f"{serve_transfer_policy(1)}; peak bytes in use "
+             f"{peak_bytes(devices)}")
+
+    # prefill into ML_FROM as the server installs a refill, then save it
+    prefill = jax.jit(api.prefill)
+    logits, one = prefill(dev["params"], jax.numpy.asarray(prompt)[None],
+                          api.init_cache(1, ML_MAX_SEQ))
+    prefill_logits = jax.device_get(logits[0, -1])
+    cache = {k: (v.at[ML_FROM].set(one[k][0]) if k == "pos"
+                 else v.at[:, ML_FROM].set(one[k][:, 0]))
+             for k, v in dev["cache"].items()}
+    saved = {k: np.asarray(jax.device_get(v[ML_FROM] if k == "pos"
+                                          else v[:, ML_FROM]))
+             for k, v in cache.items()}
+    del cache, one, dev
+
+    # the resume op: write the session into ML_TO, mark_dirty, to_device
+    for k, v in saved.items():
+        if k == "pos":
+            host["cache"][k][ML_TO] = v
+        else:
+            host["cache"][k][:, ML_TO] = v
+    host["slots"]["rid"][ML_TO] = 0
+    host["slots"]["pos"][ML_TO] = ML_PROMPT
+    program.mark_dirty(host, "cache")
+    program.reset_ledgers()
+    t0 = time.perf_counter()
+    dev = jax.block_until_ready(program.to_device(host))
+    wall = time.perf_counter() - t0
+    ledger = program.merged_ledger()
+    check(all(same_bytes(dev["cache"][k][ML_TO] if k == "pos"
+                         else dev["cache"][k][:, ML_TO], v)
+              for k, v in saved.items()),
+          "the resumed slot's rows differ from the saved session")
+    check(int(saved["pos"]) == ML_PROMPT, f"saved pos {saved['pos']}")
+    say("m", f"resume pass: session {sum(v.nbytes for v in saved.values())} "
+             f"B into slot {ML_TO}; h2d {ledger.h2d_bytes} B, wall "
+             f"{wall:.3f} s (informational); resumed rows byte-exact")
+    bf16 = [prefill_logits] + _decode_from(api, dev["params"], dev["cache"],
+                                           ML_TO, follow)
+    params = dev["params"]
+    del dev
+    program.clear()
+    session.clear()
+    gc.collect()
+    n, s = clock.take()
+    say("m", f"bf16 prefill, resume and decode: {n} compiles ({s:.3f} s); "
+             f"peak bytes in use {peak_bytes(devices)}")
+
+    with jax.default_matmul_precision("highest"):
+        api32 = model_api(dict(cfg, compute_dtype="float32"))
+        logits, one = jax.jit(api32.prefill)(
+            params, jax.numpy.asarray(prompt)[None],
+            api32.init_cache(1, ML_MAX_SEQ))
+        f32 = [logits[0, -1]] + _decode_from(api32, params, one, 0, follow)
+        del one
+        want = ref.forward(cfg, params, ids, block_q=ML_BLOCK_Q, jit=True,
+                           logits_at=range(ML_PROMPT - 1,
+                                           ML_PROMPT + ML_DECODE))
+    e16 = _rel_errors(bf16, want)
+    e32 = _rel_errors(f32, want)
+    med16 = float(np.median(e16))
+    n, s = clock.take()
+    peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+               for d in devices)
+    out = {"prompt": ML_PROMPT, "decoded": ML_DECODE,
+           "f32_max_rel_err": max(e32), "f32_prefill_rel_err": e32[0],
+           "bf16_median_rel_err": med16, "bf16_max_rel_err": max(e16),
+           "bf16_prefill_rel_err": e16[0], "peak_bytes_in_use": peak}
+    say("m", f"logits against the reference over {ML_PROMPT} + "
+             f"{ML_DECODE} tokens: f32 max {max(e32):.3e} (bound "
+             f"{TOL_ML_F32:g}), bf16 median {med16:.3e} (bound "
+             f"{TOL_ML_BF16_MEDIAN:g}), bf16 max {max(e16):.3e}; per "
+             f"position f32 {[f'{e:.2e}' for e in e32]}, bf16 "
+             f"{[f'{e:.2e}' for e in e16]}; {n} compiles ({s:.3f} s)")
+    check(max(e32) <= TOL_ML_F32, "f32 logits disagree with the reference")
+    check(med16 <= TOL_ML_BF16_MEDIAN,
+          "bf16 logits disagree with the reference")
+    return out
+
+
+def run(chips: int, arch: str = ARCH) -> dict:
     src = os.path.join(ROOT, "src")
     if not os.path.isdir(os.path.join(src, "repro")):
         raise SmokeFailure(f"no repro package under {src}: run chip_smoke.py "
                            f"from a checkout of the repository")
-    sys.path.insert(0, src)
+    sys.path[:0] = [src, ROOT]
     import jax
     from repro.jaxenv import use_compile_cache
     from repro.models import registry
@@ -393,6 +578,12 @@ def run(chips: int) -> dict:
     say("env", f"jax {jax.__version__}, {len(devices)} x {dev0.device_kind}, "
                f"compile cache {cache_dir}")
     clock = CompileClock()
+    out = {"device": {"platform": dev0.platform, "kind": dev0.device_kind,
+                      "count": len(devices)}}
+    if arch == MOONLIGHT:
+        check(chips == 1, f"{MOONLIGHT} runs on one chip")
+        out["moonlight"] = phase_moonlight(devices[:1], clock)
+        return out
     api = registry.get(ARCH)
     if chips == 4:
         phase_dp4(api, devices, clock)
@@ -400,8 +591,7 @@ def run(chips: int) -> dict:
         phase_deep_copy(api, devices, clock)
         gc.collect()
         phase_serve(api, devices, clock)
-    return {"platform": dev0.platform, "kind": dev0.device_kind,
-            "count": len(devices)}
+    return out
 
 
 def main(argv=None) -> int:
@@ -409,14 +599,16 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the @dp4 sharded staging path and its "
                          "one-device comparison")
+    ap.add_argument("--arch", choices=(ARCH, MOONLIGHT), default=ARCH,
+                    help=f"{MOONLIGHT}: phase (m) only")
     args = ap.parse_args(argv)
     try:
-        device = run(args.chips)
+        out = run(args.chips, args.arch)
     except Exception:
         traceback.print_exc()
         print("chip_smoke: FAILED", file=sys.stderr)
         return 1
-    print(json.dumps({"ok": True, "device": device}), flush=True)
+    print(json.dumps(dict({"ok": True}, **out)), flush=True)
     return 0
 
 

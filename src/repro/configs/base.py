@@ -23,10 +23,24 @@ class ModelConfig:
     head_dim: int = 0                # 0 -> d_model // num_heads
 
     # MoE
-    num_experts: int = 0
+    num_experts: int = 0                 # the router's width
     experts_per_token: int = 0
-    moe_capacity_factor: float = 1.25
+    moe_capacity_factor: float = 1.25    # 0: dropless (grouped matmuls)
     moe_dense_residual: bool = False     # arctic: dense FFN residual alongside MoE
+    experts_held: int = 0                # routed experts this chip holds; 0: all
+    expert_offset: int = 0               # id of the first expert held
+    n_shared_experts: int = 0            # always-on experts: one MLP n x d_ff wide
+    first_k_dense_replace: int = 0       # leading layers with a dense MLP ...
+    dense_d_ff: int = 0                  # ... this wide
+    scoring_func: str = "softmax"        # softmax | sigmoid (+ a correction
+                                         # bias for the choice only: noaux_tc)
+    routed_scaling_factor: float = 1.0   # routed experts' gates scaled by this
+
+    # multi-head latent attention (deepseek-v2/v3): kv_lora_rank > 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # SSM (mamba2 / zamba2)
     ssm_state: int = 0
@@ -39,6 +53,7 @@ class ModelConfig:
     qkv_bias: bool = False               # qwen1.5
     gated_mlp: bool = True               # False -> LayerNorm+GeLU (starcoder2)
     norm: str = "rmsnorm"                # rmsnorm | layernorm
+    rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
     tie_embeddings: bool = False
 
@@ -76,6 +91,10 @@ class ModelConfig:
         return self.head_dim or (self.d_model // max(1, self.num_heads))
 
     @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -111,6 +130,14 @@ class ModelConfig:
             vocab_size=257,
             num_experts=min(self.num_experts, 4) if self.num_experts else 0,
             experts_per_token=min(self.experts_per_token, 2) if self.experts_per_token else 0,
+            experts_held=min(self.experts_held, 2) if self.experts_held else 0,
+            expert_offset=2 if self.expert_offset else 0,
+            first_k_dense_replace=min(self.first_k_dense_replace, 1),
+            dense_d_ff=128 if self.dense_d_ff else 0,
+            kv_lora_rank=32 if self.kv_lora_rank else 0,
+            qk_nope_head_dim=16 if self.qk_nope_head_dim else 0,
+            qk_rope_head_dim=8 if self.qk_rope_head_dim else 0,
+            v_head_dim=16 if self.v_head_dim else 0,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_head_dim=16,
             ssm_chunk=8,

@@ -82,6 +82,11 @@ def layer_bodies(api: ModelApi, shape: InputShape, mesh, rules
     kv_axes = ("batch", "kv_seq", "kv_heads", "head_dim")
 
     def attn_cache():
+        if cfg.kv_lora_rank:        # latent attention: one row per token
+            lat = ("batch", "kv_seq", None)
+            abs_c = {"c_kv": _sds((B, S_cache, cfg.kv_lora_rank), cdt),
+                     "k_pe": _sds((B, S_cache, cfg.qk_rope_head_dim), cdt)}
+            return abs_c, sh({"c_kv": lat, "k_pe": lat}, abs_c)
         abs_c = {"k": _sds(kv_shape, cdt), "v": _sds(kv_shape, cdt)}
         sh_c = sh({"k": kv_axes, "v": kv_axes}, abs_c)
         return abs_c, sh_c
@@ -91,29 +96,35 @@ def layer_bodies(api: ModelApi, shape: InputShape, mesh, rules
 
     # ---------------- dense / moe / vlm ----------------
     if cfg.family in ("dense", "moe", "vlm"):
-        spec = lm_mod._attn_block_specs(cfg)
-        p_abs = abstract_params(spec, pdt)
-        p_sh = sh(param_axes(spec), p_abs)
+        k = lm_mod._n_dense(cfg)
+        for kind, moe, trips in (("dense_block", False, k),
+                                 ("attn_block", True, cfg.num_layers - k)):
+            if not trips:
+                continue
+            spec = lm_mod._attn_block_specs(cfg, moe=moe)
+            p_abs = abstract_params(spec, pdt)
+            p_sh = sh(param_axes(spec), p_abs)
 
-        if mode == "train":
-            def apply_fn(p, x, pos):
-                y, _, _ = lm_mod._attn_block(cfg, p, x, positions=pos,
-                                             cache=None, kv_valid_len=None,
-                                             aux=jnp.zeros((), jnp.float32))
-                return y
-            record("attn_block", cfg.num_layers, _grad_wrap(apply_fn, 2, cfg),
-                   (p_abs, x_abs, pos_abs), (p_sh, x_sh, pos_sh))
-        else:
-            c_abs, c_sh = attn_cache()
+            if mode == "train":
+                def apply_fn(p, x, pos):
+                    y, _, _ = lm_mod._attn_block(
+                        cfg, p, x, positions=pos, cache=None,
+                        kv_valid_len=None, aux=jnp.zeros((), jnp.float32))
+                    return y
+                record(kind, trips, _grad_wrap(apply_fn, 2, cfg),
+                       (p_abs, x_abs, pos_abs), (p_sh, x_sh, pos_sh))
+            else:
+                c_abs, c_sh = attn_cache()
 
-            def apply_fn(p, x, pos, cache):
-                y, _, _ = lm_mod._attn_block(
-                    cfg, p, x, positions=pos, cache=cache,
-                    kv_valid_len=pos[:, -1] + 1,
-                    aux=jnp.zeros((), jnp.float32))
-                return y
-            record("attn_block", cfg.num_layers, apply_fn,
-                   (p_abs, x_abs, pos_abs, c_abs), (p_sh, x_sh, pos_sh, c_sh))
+                def apply_fn(p, x, pos, cache):
+                    y, _, _ = lm_mod._attn_block(
+                        cfg, p, x, positions=pos, cache=cache,
+                        kv_valid_len=pos[:, -1] + 1,
+                        aux=jnp.zeros((), jnp.float32))
+                    return y
+                record(kind, trips, apply_fn,
+                       (p_abs, x_abs, pos_abs, c_abs),
+                       (p_sh, x_sh, pos_sh, c_sh))
 
     # ---------------- ssm / hybrid ----------------
     elif cfg.family in ("ssm", "hybrid"):

@@ -43,9 +43,15 @@ def apply_norm(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array) -> jax.Array:
         y = (xf - mu) * jax.lax.rsqrt(var + 1e-5)
         y = y * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
     else:
-        ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(ms + 1e-6) * p["scale"].astype(jnp.float32)
+        y = rms_norm(xf, p["scale"], cfg.rms_norm_eps)
     return y.astype(x.dtype)
+
+
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMS norm in float32 (the caller casts the result back)."""
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return xf * jax.lax.rsqrt(ms + eps) * scale.astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -97,6 +103,49 @@ def _masked_softmax(scores, mask):
     return e / (jnp.sum(e, axis=-1, keepdims=True) + 1e-30)
 
 
+def cache_write(cache: jax.Array, new: jax.Array,
+                positions: jax.Array) -> jax.Array:
+    """``cache`` (B, S_max, ...) with ``new`` (B, S, ...) written at each
+    row's positions (``positions``: (B, S) or broadcastable, contiguous per
+    row from its first).
+
+    No batch-indexed scatter: a scatter keyed on global batch indices forces
+    GSPMD to all-gather the whole cache over the batch axis (~8.6 GB/layer
+    at 32k prefill — EXPERIMENTS.md §Perf #3).  Positions are contiguous per
+    row (offset + arange(S)), so the update is a gather along the UNSHARDED
+    step dim + mask blend, which partitions cleanly over batch and kv_seq.
+    """
+    B, S = new.shape[:2]
+    S_max = cache.shape[1]
+    offset = jnp.broadcast_to(positions, (B, S)).astype(jnp.int32)[:, 0]
+    idx = jnp.arange(S_max, dtype=jnp.int32)[None, :] - offset[:, None]
+    in_range = (idx >= 0) & (idx < S)                            # (B, S_max)
+    tail = (1,) * (new.ndim - 2)
+    take = jnp.clip(idx, 0, S - 1).reshape((B, S_max) + tail)
+    src = jnp.take_along_axis(new.astype(cache.dtype), take, axis=1)
+    return jnp.where(in_range.reshape((B, S_max) + tail), src, cache)
+
+
+def over_query_blocks(fn, qs, pos: jax.Array, block_q: int):
+    """``fn(qs, pos)`` one block of ``block_q`` queries at a time
+    (``lax.map``), so scores take O(block_q * Sk) memory and not O(S * Sk).
+    ``qs``: a tuple of arrays with the queries on axis 1, ``pos``: (B, S);
+    ``fn`` returns (B, bq, ...)."""
+    B, S = pos.shape
+    if S <= block_q:
+        return fn(qs, pos)
+    nb = -(-S // block_q)
+    pad = nb * block_q - S
+
+    def split(a):
+        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        return a.reshape((B, nb, block_q) + a.shape[2:]).swapaxes(0, 1)
+
+    out = jax.lax.map(lambda args: fn(*args),
+                      (tuple(split(q) for q in qs), split(pos)))
+    return out.swapaxes(0, 1).reshape((B, nb * block_q) + out.shape[3:])[:, :S]
+
+
 def multihead_attention(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array, *,
                         positions: jax.Array,
                         kv_cache: Optional[Dict[str, Any]] = None,
@@ -136,23 +185,8 @@ def multihead_attention(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array, *,
 
     new_cache = None
     if kv_cache is not None and kv_x is None:
-        # Cache write WITHOUT a batch-indexed scatter: a scatter keyed on
-        # global batch indices forces GSPMD to all-gather the whole KV cache
-        # over the batch axis (~8.6 GB/layer at 32k prefill — EXPERIMENTS.md
-        # §Perf #3).  Positions are contiguous per row (offset + arange(S)),
-        # so the update is a gather along the UNSHARDED step dim + mask
-        # blend, which partitions cleanly over batch and kv_seq.
-        S_max = kv_cache["k"].shape[1]
-        pos_b = jnp.broadcast_to(positions, (B, S)).astype(jnp.int32)
-        offset = pos_b[:, 0]                                     # (B,)
-        idx = jnp.arange(S_max, dtype=jnp.int32)[None, :] - offset[:, None]
-        in_range = (idx >= 0) & (idx < S)                        # (B, S_max)
-        take = jnp.clip(idx, 0, S - 1)[:, :, None, None]
-        src_k = jnp.take_along_axis(k.astype(kv_cache["k"].dtype), take, axis=1)
-        src_v = jnp.take_along_axis(v.astype(kv_cache["v"].dtype), take, axis=1)
-        sel = in_range[:, :, None, None]
-        ck = jnp.where(sel, src_k, kv_cache["k"])
-        cv = jnp.where(sel, src_v, kv_cache["v"])
+        ck = cache_write(kv_cache["k"], k, positions)
+        cv = cache_write(kv_cache["v"], v, positions)
         new_cache = {"k": ck, "v": cv}
         k, v = ck, cv
 
@@ -175,20 +209,8 @@ def multihead_attention(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array, *,
         return jnp.einsum("bkgqs,bskh->bqkgh", probs,
                           v.astype(jnp.float32)).astype(x.dtype)
 
-    if S <= block_q:
-        pos_b = jnp.broadcast_to(positions, (B, S))
-        ctx = block_attn(qr, pos_b)
-    else:
-        nb = -(-S // block_q)
-        pad = nb * block_q - S
-        qp = jnp.pad(qr, ((0, 0), (0, pad), (0, 0), (0, 0), (0, 0)))
-        pos_b = jnp.broadcast_to(positions, (B, S))
-        pp = jnp.pad(pos_b, ((0, 0), (0, pad)))
-        qblocks = qp.reshape(B, nb, block_q, kv, g, hd).swapaxes(0, 1)
-        pblocks = pp.reshape(B, nb, block_q).swapaxes(0, 1)
-        ctx = jax.lax.map(lambda args: block_attn(*args), (qblocks, pblocks))
-        ctx = ctx.swapaxes(0, 1).reshape(B, nb * block_q, kv, g, hd)[:, :S]
-
+    ctx = over_query_blocks(lambda qs, qpos: block_attn(qs[0], qpos), (qr,),
+                            jnp.broadcast_to(positions, (B, S)), block_q)
     ctx = ctx.reshape(B, S, h, hd)
     out = jnp.einsum("bshk,hkd->bsd", ctx, p["wo"].astype(x.dtype))
     return out, new_cache
@@ -198,8 +220,9 @@ def multihead_attention(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array, *,
 # MLP
 # ---------------------------------------------------------------------------
 
-def mlp_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
-    d, f = cfg.d_model, cfg.d_ff
+def mlp_specs(cfg: ModelConfig, d_ff: Optional[int] = None
+              ) -> Dict[str, ParamSpec]:
+    d, f = cfg.d_model, d_ff or cfg.d_ff
     if cfg.gated_mlp:
         return {"w_gate": ParamSpec((d, f), ("embed", "mlp")),
                 "w_up": ParamSpec((d, f), ("embed", "mlp")),

@@ -2,7 +2,9 @@
 
 One parameterized implementation composes the block zoo:
   dense   — [norm, GQA attn, norm, (Swi)GLU MLP] x L        (llama/qwen/granite/starcoder/phi3)
-  moe     — MLP replaced by top-k expert layer (+ optional dense residual, arctic)
+  moe     — MLP replaced by top-k expert layer (+ optional dense residual, arctic;
+            leading dense layers, shared experts and latent attention (MLA),
+            moonlight)
   vlm     — dense backbone; precomputed patch embeddings prepended (phi-3-vision)
   ssm     — [norm, Mamba2 SSD] x L                           (mamba2)
   hybrid  — Mamba2 stack + one weight-SHARED attention block every
@@ -23,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import layers as L
+from . import mla as MLA
 from . import moe as MOE
 from . import ssm as SSM
 from .pspec import constrain
@@ -41,16 +44,24 @@ def _stack(spec_tree: Any, n: int) -> Any:
         spec_tree, is_leaf=is_spec)
 
 
-def _attn_block_specs(cfg: ModelConfig) -> Dict[str, Any]:
-    block = {"ln1": L.norm_specs(cfg), "attn": L.attention_specs(cfg),
-             "ln2": L.norm_specs(cfg)}
-    if cfg.family == "moe":
+def _attn_block_specs(cfg: ModelConfig, moe: bool = True) -> Dict[str, Any]:
+    """One attention block; ``moe=False`` is a leading dense layer of a MoE
+    model (``first_k_dense_replace``), ``dense_d_ff`` wide."""
+    attn = MLA.mla_specs(cfg) if cfg.kv_lora_rank else L.attention_specs(cfg)
+    block = {"ln1": L.norm_specs(cfg), "attn": attn, "ln2": L.norm_specs(cfg)}
+    if cfg.family != "moe":
+        block["mlp"] = L.mlp_specs(cfg)
+    elif not moe:
+        block["mlp"] = L.mlp_specs(cfg, cfg.dense_d_ff)
+    else:
         block["moe"] = MOE.moe_specs(cfg)
         if cfg.moe_dense_residual:
             block["mlp"] = L.mlp_specs(cfg)
-    else:
-        block["mlp"] = L.mlp_specs(cfg)
     return block
+
+
+def _n_dense(cfg: ModelConfig) -> int:
+    return cfg.first_k_dense_replace if cfg.family == "moe" else 0
 
 
 def _ssm_block_specs(cfg: ModelConfig) -> Dict[str, Any]:
@@ -61,7 +72,10 @@ def spec_tree(cfg: ModelConfig) -> Dict[str, Any]:
     tree: Dict[str, Any] = {"embed": L.embed_specs(cfg),
                             "final_norm": L.norm_specs(cfg)}
     if cfg.family in ("dense", "moe", "vlm"):
-        tree["blocks"] = _stack(_attn_block_specs(cfg), cfg.num_layers)
+        k = _n_dense(cfg)
+        if k:
+            tree["dense_blocks"] = _stack(_attn_block_specs(cfg, moe=False), k)
+        tree["blocks"] = _stack(_attn_block_specs(cfg), cfg.num_layers - k)
     elif cfg.family == "ssm":
         tree["blocks"] = _stack(_ssm_block_specs(cfg), cfg.num_layers)
     elif cfg.family == "hybrid":
@@ -104,7 +118,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, abstract_only=False):
           else lambda sh, dt: jnp.zeros(sh, dt))
     kvhd = (cfg.num_kv_heads, cfg.resolved_head_dim)
     cache: Dict[str, Any] = {"pos": mk((batch,), jnp.int32)}
-    if cfg.family in ("dense", "moe", "vlm"):
+    if cfg.family in ("dense", "moe", "vlm") and cfg.kv_lora_rank:
+        # one latent row per token and layer, shared by every head
+        lead = (cfg.num_layers, batch, max_seq)
+        cache["c_kv"] = mk(lead + (cfg.kv_lora_rank,), kv_dtype)
+        cache["k_pe"] = mk(lead + (cfg.qk_rope_head_dim,), kv_dtype)
+    elif cfg.family in ("dense", "moe", "vlm"):
         cache["k"] = mk((cfg.num_layers, batch, max_seq) + kvhd, kv_dtype)
         cache["v"] = mk((cfg.num_layers, batch, max_seq) + kvhd, kv_dtype)
     elif cfg.family == "ssm":
@@ -138,20 +157,24 @@ def _remat(cfg: ModelConfig, fn):
 
 def _attn_block(cfg, p, x, *, positions, cache, kv_valid_len, aux):
     h = L.apply_norm(cfg, p["ln1"], x)
-    attn_out, new_cache = L.multihead_attention(
-        cfg, p["attn"], h, positions=positions, kv_cache=cache,
-        kv_valid_len=kv_valid_len)
+    if cfg.kv_lora_rank:
+        attn_out, new_cache = MLA.mla_attention(
+            cfg, p["attn"], h, positions=positions, cache=cache,
+            kv_valid_len=kv_valid_len)
+    else:
+        attn_out, new_cache = L.multihead_attention(
+            cfg, p["attn"], h, positions=positions, kv_cache=cache,
+            kv_valid_len=kv_valid_len)
     x = x + attn_out
     h = L.apply_norm(cfg, p["ln2"], x)
-    if cfg.family == "moe":
-        moe_out, moe_aux = MOE.apply_moe(cfg, p["moe"], h)
+    if "moe" in p:
+        out, moe_aux = MOE.apply_moe(cfg, p["moe"], h)
         aux = aux + moe_aux["moe_aux_loss"]
-        if cfg.moe_dense_residual:
-            moe_out = moe_out + L.apply_mlp(cfg, p["mlp"], h)
-        x = x + moe_out
+        if "mlp" in p:        # arctic's dense residual
+            out = out + L.apply_mlp(cfg, p["mlp"], h)
     else:
-        x = x + L.apply_mlp(cfg, p["mlp"], h)
-    x = constrain(x, "batch", None, None)
+        out = L.apply_mlp(cfg, p["mlp"], h)
+    x = constrain(x + out, "batch", None, None)
     return x, new_cache, aux
 
 
@@ -168,10 +191,9 @@ def _layer_cache(cache, keys):
     return {k: cache[k] for k in keys if k in cache}
 
 
-def _run_attn_stack(cfg, blocks, x, *, positions, cache, kv_valid_len):
-    """lax.scan over stacked attention blocks (dense/moe/vlm)."""
-    aux0 = jnp.zeros((), jnp.float32)
-    layer_cache = _layer_cache(cache, ("k", "v"))
+def _scan_attn_blocks(cfg, blocks, x, aux, layer_cache, *, positions,
+                      kv_valid_len):
+    """lax.scan over one stack of attention blocks."""
     block_fn = _remat(cfg, functools.partial(
         _attn_block, cfg, positions=positions, kv_valid_len=kv_valid_len))
 
@@ -180,7 +202,7 @@ def _run_attn_stack(cfg, blocks, x, *, positions, cache, kv_valid_len):
             x, aux = carry
             x, _, aux = block_fn(p, x, cache=None, aux=aux)
             return (x, aux), None
-        (x, aux), _ = jax.lax.scan(body_nc, (x, aux0), blocks)
+        (x, aux), _ = jax.lax.scan(body_nc, (x, aux), blocks)
         return x, None, aux
 
     def body(carry, xs):
@@ -189,8 +211,33 @@ def _run_attn_stack(cfg, blocks, x, *, positions, cache, kv_valid_len):
         x, new_c, aux = block_fn(p, x, cache=c, aux=aux)
         return (x, aux), new_c
 
-    (x, aux), new_cache = jax.lax.scan(body, (x, aux0), (blocks, layer_cache))
+    (x, aux), new_cache = jax.lax.scan(body, (x, aux), (blocks, layer_cache))
     return x, new_cache, aux
+
+
+def _run_attn_stack(cfg, params, x, *, positions, cache, kv_valid_len):
+    """The leading dense blocks (if any), then the stacked attention blocks
+    (dense/moe/vlm): one scan each, over their layers of the cache."""
+    aux = jnp.zeros((), jnp.float32)
+    keys = ("c_kv", "k_pe") if cfg.kv_lora_rank else ("k", "v")
+    layer_cache = _layer_cache(cache, keys)
+    k = _n_dense(cfg)
+    parts = []
+    for name, lo, hi in (("dense_blocks", 0, k),
+                         ("blocks", k, cfg.num_layers)):
+        if hi == lo:
+            continue
+        part = (None if layer_cache is None
+                else {n: v[lo:hi] for n, v in layer_cache.items()})
+        x, new_part, aux = _scan_attn_blocks(
+            cfg, params[name], x, aux, part, positions=positions,
+            kv_valid_len=kv_valid_len)
+        parts.append(new_part)
+    if layer_cache is None:
+        return x, None, aux
+    if len(parts) == 1:
+        return x, parts[0], aux
+    return x, {n: jnp.concatenate([p[n] for p in parts]) for n in keys}, aux
 
 
 def _run_ssm_stack(cfg, params, x, *, positions, cache, kv_valid_len):
@@ -287,7 +334,7 @@ def forward(cfg: ModelConfig, params, tokens, *, positions=None, cache=None,
     x = constrain(x, "batch", None, None)
 
     if cfg.family in ("dense", "moe", "vlm"):
-        x, new_cache, aux = _run_attn_stack(cfg, params["blocks"], x,
+        x, new_cache, aux = _run_attn_stack(cfg, params, x,
                                             positions=positions, cache=cache,
                                             kv_valid_len=kv_valid_len)
     else:

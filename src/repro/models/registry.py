@@ -100,6 +100,8 @@ class ModelApi:
         for k, v in cache.items():
             if k in ("k", "v"):
                 out[k] = ("layers", "batch", "kv_seq", "kv_heads", "head_dim")
+            elif k in ("c_kv", "k_pe"):
+                out[k] = ("layers", "batch", "kv_seq", None)
             elif k == "state":
                 out[k] = ("layers", "batch", "ssm_heads", None, "ssm_state")
             elif k == "conv":
